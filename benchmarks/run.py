@@ -10,9 +10,10 @@ from __future__ import annotations
 import argparse
 import sys
 import time
+from pathlib import Path
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale seed counts (slow on CPU)")
@@ -22,6 +23,10 @@ def main() -> None:
                          "large_n,cost_aware,roofline")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+
+    from repro.compile_cache import enable_persistent_cache
+
+    enable_persistent_cache(Path(__file__).resolve().parents[1])
 
     from benchmarks import async_strategies, bo_vs_random, early_stopping
     from benchmarks import gp_perf, log_scaling, roofline_report, warm_start
@@ -67,17 +72,23 @@ def main() -> None:
         suites.append(("roofline", roofline_report.run))
 
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites:
         t0 = time.perf_counter()
         try:
             rows = fn()
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report the row, fail at exit
             print(f"{name}_ERROR,0,{type(e).__name__}:{e}", flush=True)
+            failed.append(name)
             continue
         for r in rows:
             print(f"{r[0]},{r[1]:.1f},{r[2]}", flush=True)
         sys.stderr.write(f"[{name}] {time.perf_counter()-t0:.1f}s\n")
+    if failed:
+        sys.stderr.write(f"failed suites: {','.join(failed)}\n")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -20,12 +20,17 @@ Fig. 1). This demo is that deployment shape in miniature:
 
 With ``--ports`` the demo instead connects to replicas you started
 yourself (``python -m repro.distributed.engine_server --port 7341``) and
-skips the kill (it won't shoot processes it doesn't own).
+skips the kill (it won't shoot processes it doesn't own). On a host with
+accelerators start one such process per host, not per replica: a chip
+belongs to the one process that touched JAX first, so the replicas of a
+host share one process, each ``EngineServer(device=...)`` on its own chip —
+which is how the self-hosted demo starts its two replicas.
 """
 
 import argparse
 import math
 
+import jax
 import numpy as np
 
 from repro.core import BOConfig, Continuous, SearchSpace, Tuner, TuningJobConfig
@@ -61,8 +66,10 @@ def main() -> None:
     if args.ports:
         addresses = [("127.0.0.1", int(p)) for p in args.ports.split(",")]
     else:
-        servers = [EngineServer(service_config=engine_cfg).start()
-                   for _ in range(2)]
+        devices = jax.devices()  # one replica per chip, in this process
+        servers = [EngineServer(service_config=engine_cfg,
+                                device=devices[i % len(devices)]).start()
+                   for i in range(2)]
         addresses = [s.address for s in servers]
     print(f"replica fleet: {addresses}")
 

@@ -679,6 +679,7 @@ def bo_config_to_wire(cfg: BOConfig) -> Dict[str, Any]:
         "refit_every": cfg.refit_every,
         "incremental": cfg.incremental,
         "fit_backend": cfg.fit_backend,
+        "fit_on_host": cfg.fit_on_host,
         "num_scalarizations": cfg.num_scalarizations,
         "fantasy_block": cfg.fantasy_block,
         "posterior_backend": cfg.posterior_backend,
@@ -705,6 +706,7 @@ def bo_config_from_wire(blob: Dict[str, Any]) -> BOConfig:
         refit_every=int(blob["refit_every"]),
         incremental=bool(blob["incremental"]),
         fit_backend=blob["fit_backend"],
+        fit_on_host=bool(blob.get("fit_on_host", True)),
         num_scalarizations=int(blob.get("num_scalarizations", 16)),
         fantasy_block=bool(blob.get("fantasy_block", False)),
         posterior_backend=blob.get("posterior_backend", "exact"),

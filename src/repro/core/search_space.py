@@ -80,11 +80,16 @@ class Continuous:
         u = min(1.0, max(0.0, float(u)))
         if self.scaling == ScalingType.LOG:
             lo, hi = math.log(self.low), math.log(self.high)
-            return float(math.exp(lo + u * (hi - lo)))
-        if self.scaling == ScalingType.REVERSE_LOG:
+            v = math.exp(lo + u * (hi - lo))
+        elif self.scaling == ScalingType.REVERSE_LOG:
             lo, hi = math.log1p(-self.low), math.log1p(-self.high)
-            return float(1.0 - math.exp(lo + u * (hi - lo)))
-        return float(self.low + u * (self.high - self.low))
+            v = 1.0 - math.exp(lo + u * (hi - lo))
+        else:
+            v = self.low + u * (self.high - self.low)
+        # exp/log round trips can land an ulp outside [low, high] at the
+        # cube's faces (e.g. 0.10000000000000006 for high = 0.1); a decoded
+        # config must be a valid point of its own space.
+        return float(min(self.high, max(self.low, v)))
 
     @property
     def encoded_width(self) -> int:

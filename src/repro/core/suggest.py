@@ -110,6 +110,15 @@ class BOConfig:
       fitted posterior — ``backend="pallas"`` and ``backend="xla"`` engines
       walk bit-identical GPHP chains and differ only in how anchors are
       scored (the e2e invariance tests rely on this).
+
+    ``fit_on_host`` places GPHP fitting (slice chain or MAP) on the host's
+    CPU device, in float64 like the rest of the GP, whatever the default
+    device is; the draws come back to the caller's default device. A chain
+    is ~2400 serial log-density probes, each an O(n³) Cholesky: on a TPU
+    v5e, which emulates float64, one probe at n = 512 took ~175 ms, so a
+    paper-default refit took ~7 minutes against ~17 s on a CPU. The Matérn
+    Pallas kernel would only be interpreted there, so ``fit_backend=
+    "pallas"`` needs ``fit_on_host=False``.
     """
 
     num_init: int = 3  # Sobol initial design before the GP takes over
@@ -127,6 +136,7 @@ class BOConfig:
     # acq.backend and reset to None, so a later dataclasses.replace(acq=...)
     # is never stomped by a stale shorthand
     fit_backend: str = "xla"  # gram backend for GPHP fitting/factorization
+    fit_on_host: bool = True  # GPHP fitting on the host CPU device
     num_scalarizations: int = 16  # Pareto mode: simplex weight draws/decision
     fantasy_block: bool = False  # fold the pending set with one rank-k
     # blocked append instead of k rank-1 borders ("liar" strategy only);
@@ -159,6 +169,11 @@ class BOConfig:
             raise ValueError(
                 f"unknown posterior_backend {self.posterior_backend!r} "
                 "(expected 'exact' or 'subset')"
+            )
+        if self.fit_on_host and self.fit_backend == "pallas":
+            raise ValueError(
+                "fit_backend='pallas' needs fit_on_host=False: on the host "
+                "the Matérn kernel would run in the Pallas interpreter"
             )
         if self.max_inducing < 2:
             raise ValueError("max_inducing must be at least 2")
@@ -1604,18 +1619,22 @@ class BOSuggester:
             init = jnp.clip(prev, bounds.lower + 1e-4, bounds.upper - 1e-4)
 
         if cfg.gphp_method == "map":
-            best = map_gphps(
-                xj, yj, mj, bounds, init, self._next_key(), cfg.eb_config,
-                cfg.fit_backend,
-            )
-            self._set_chain_state(chain_slot, np.asarray(best))
-            return best[None, :]
-        samples = mcmc_gphps(
-            xj, yj, mj, bounds, init, self._next_key(), cfg.slice_config,
-            cfg.fit_backend,
-        )
-        self._set_chain_state(chain_slot, np.asarray(samples[-1]))
-        return samples
+            fit, fit_cfg = map_gphps, cfg.eb_config
+        else:
+            fit, fit_cfg = mcmc_gphps, cfg.slice_config
+        args = (xj, yj, mj, bounds, init, self._next_key())
+        if cfg.fit_on_host:
+            host = jax.devices("cpu")[0]
+            with jax.default_device(host):
+                out = fit(*jax.device_put(args, host), fit_cfg, cfg.fit_backend)
+            out = jnp.asarray(np.asarray(out))
+        else:
+            out = fit(*args, fit_cfg, cfg.fit_backend)
+        if cfg.gphp_method == "map":
+            self._set_chain_state(chain_slot, np.asarray(out))
+            return out[None, :]
+        self._set_chain_state(chain_slot, np.asarray(out[-1]))
+        return out
 
     def _set_chain_state(
         self, chain_slot: Optional[int], state: np.ndarray

@@ -32,17 +32,27 @@ engine itself is the bottleneck, not the framing). Run a replica from the
 CLI::
 
     PYTHONPATH=src python -m repro.distributed.engine_server --port 7341
+
+A chip belongs to one process. On a host with accelerators, run one replica
+process per host and give it one ``EngineServer(device=...)`` per chip (see
+``examples/remote_fleet.py``), rather than one process per replica.
 """
 
 from __future__ import annotations
 
 import argparse
+import contextlib
+import socket
 import socketserver
 import threading
 import time
 import uuid
+from pathlib import Path
 from typing import Any, Dict, Optional, Tuple
 
+import jax
+
+from repro.compile_cache import enable_persistent_cache
 from repro.core import telemetry
 from repro.core.rpc import (
     EngineRestoreReply,
@@ -107,6 +117,10 @@ class EngineServer:
             request for a job renews its lease; a job idle longer than this
             becomes adoptable by another client.
         clock: monotonic time source (injectable for lease tests).
+        device: the JAX device this replica's engine work runs on (default:
+            JAX's default device). A chip belongs to one process, so a host
+            with several chips serves them as several replicas in one
+            process, one ``EngineServer(device=...)`` per chip.
     """
 
     def __init__(
@@ -117,24 +131,38 @@ class EngineServer:
         service_config: Optional[ServiceConfig] = None,
         lease_ttl: float = DEFAULT_LEASE_TTL,
         clock=time.monotonic,
+        device=None,
     ):
         self.service = SelectionService(service_config or ServiceConfig())
+        self.device = device
         self.lease_ttl = float(lease_ttl)
         self._clock = clock
         self._lock = threading.RLock()
         self._leases: Dict[str, _Lease] = {}
+        self._conns: set = set()  # live client sockets (closed on shutdown)
+        self._conns_lock = threading.Lock()
         server = self
 
         class _Handler(socketserver.StreamRequestHandler):
+            def setup(self) -> None:
+                super().setup()
+                with server._conns_lock:
+                    server._conns.add(self.connection)
+
+            def finish(self) -> None:
+                with server._conns_lock:
+                    server._conns.discard(self.connection)
+                super().finish()
+
             def handle(self) -> None:
-                for line in self.rfile:
-                    if not line.strip():
-                        continue
-                    try:
+                try:
+                    for line in self.rfile:
+                        if not line.strip():
+                            continue
                         self.wfile.write(server._serve_line(line))
                         self.wfile.flush()
-                    except (BrokenPipeError, ConnectionResetError):
-                        return
+                except OSError:  # the client, or shutdown(), closed it
+                    return
 
         class _TCP(socketserver.ThreadingTCPServer):
             allow_reuse_address = True
@@ -162,10 +190,18 @@ class EngineServer:
         self._tcp.serve_forever()
 
     def shutdown(self) -> None:
-        """Stop serving and close the listening socket. In tests this stands
-        in for a replica crash: live client connections die with it."""
+        """Stop serving, close the listening socket and every live client
+        connection. In tests this stands in for a replica crash: clients see
+        their socket die and fail over."""
         self._tcp.shutdown()
         self._tcp.server_close()
+        with self._conns_lock:
+            conns = list(self._conns)
+        for conn in conns:
+            try:
+                conn.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
         if self._thread is not None:
             self._thread.join(timeout=5.0)
             self._thread = None
@@ -189,7 +225,7 @@ class EngineServer:
         verb = getattr(msg, "TYPE", "unknown")
         with telemetry.span("rpc." + verb):
             try:
-                with self._lock:
+                with self._lock, self._on_device():
                     reply = self._dispatch(msg)
             except ProtocolError as e:
                 reply = ErrorReply(code=e.code, message=e.message,
@@ -206,6 +242,13 @@ class EngineServer:
             if isinstance(reply, ErrorReply):
                 telemetry.count("server.refusal." + reply.code)
         return out
+
+    def _on_device(self):
+        """Pin this request's engine work to the replica's device (the
+        setting is thread-local: other replicas' handlers are unaffected)."""
+        if self.device is None:
+            return contextlib.nullcontext()
+        return jax.default_device(self.device)
 
     def _dispatch(self, msg: Any) -> Any:
         if isinstance(msg, MetricsRequest):
@@ -469,6 +512,7 @@ def main(argv=None) -> None:
                          "REPRO_TELEMETRY=1); serve live counters via the "
                          "read-only `metrics` verb")
     args = ap.parse_args(argv)
+    enable_persistent_cache(Path(__file__).resolve().parents[3])
     if args.telemetry:
         telemetry.set_enabled(True)
     server = EngineServer(

@@ -1,9 +1,10 @@
 """Backend dispatcher for fused anchor scoring: ``acq_score``.
 
 ``backend="xla"`` is the production composition the engine always had
-(``gp.predict`` + closed-form EI/LCB, three XLA ops). ``backend="pallas"``
-pads/packs and invokes the fused kernel: one HBM pass per decision over the
-anchor grid.
+(``gp.predict`` + closed-form acquisition, three XLA ops).
+``backend="pallas"`` pads/packs and invokes the fused kernel: one pass per
+decision over the anchor grid, compiled on a TPU and interpreted on the CPU
+(``repro.kernels.resolve_interpret``; other platforms are refused).
 
 The kernel's solve is the matmul L⁻¹K*ᵀ. The inverted factor comes from the
 posterior's ``chol_inv`` cache when the engine threaded it through
@@ -14,10 +15,10 @@ sweep it feeds (the paper's grids use A ≥ n). Padded train rows extend the
 factor with an identity block (as in ``gp.incremental.grow_posterior``),
 whose inverse is again identity, keeping padded rows exactly inert.
 
-Dtype policy: in interpret mode (CPU — this container) the kernel runs in
-the posterior's own dtype, so the x64-enabled test session gets f64 parity
-against the XLA path; on a real TPU (``interpret=False``) inputs are cast to
-f32 like every other kernel in this repo.
+Dtype policy: compiled on the TPU, inputs are cast to f32 (the chip's
+dtype). Interpreted on the CPU the kernel runs in the anchors' own dtype, so
+the x64 test session gets f64 parity against the XLA path and f32 inputs
+rehearse the chip's arithmetic.
 """
 
 from __future__ import annotations
@@ -27,19 +28,12 @@ import jax.numpy as jnp
 
 from repro.core import acquisition as A
 from repro.core.gp.gp import GPPosterior, _triangular_inverse, predict
-from repro.core.gp.params import GPHyperParams
-from repro.kernels.acq_score.kernel import (
-    TILE_A,
-    acq_score_multi_pallas,
-    acq_score_pallas,
-    anchor_tile,
-)
+from repro.kernels import resolve_interpret
+from repro.kernels.acq_score.kernel import acq_score_pallas, tiling
+from repro.kernels.matern52.kernel import exp_accurate
+from repro.kernels.matern52.ops import scaled_inputs
 
 __all__ = ["acq_score", "acq_score_multi"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
@@ -51,22 +45,62 @@ def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _packed_params_batch(params: GPHyperParams, dpad: int, dt) -> tuple:
-    """(inv_ell, a, b, on, amp2) in the kernel's (S, dpad) layout."""
-    inv_ell = jnp.exp(-params.log_lengthscale.astype(dt))
-    a = jnp.exp(params.log_warp_a.astype(dt))
-    b = jnp.exp(params.log_warp_b.astype(dt))
-    identity = (jnp.abs(params.log_warp_a) < 1e-7) & (
-        jnp.abs(params.log_warp_b) < 1e-7
+def _pallas_scores(
+    post: GPPosterior,
+    alphas: jax.Array,  # (S, M, n) or (M, n) head alphas over post's factor
+    x_star: jax.Array,  # (m, d)
+    mode: str,
+    num_con: int,
+    small: tuple,  # (tcon, y_best, has_feasible, weights, y_best_w) in dt
+    interpret: bool,
+) -> jax.Array:
+    """Pad, pack and run the fused kernel: (S, m) scores (S = 1 unbatched)."""
+    batched = post.chol.ndim == 3
+    chol = post.chol if batched else post.chol[None]
+    dt = x_star.dtype if interpret else jnp.float32
+    params = jax.tree.map(
+        lambda p: (p if batched else p[None]).astype(dt), post.params
     )
-    on = jnp.where(identity, 0.0, 1.0).astype(dt)
-    # padded features: inv_ell = 0 ⇒ zero contribution to distances
-    inv_ell = _pad_to(inv_ell, dpad, 1)
-    a = _pad_to(a, dpad, 1)
-    b = _pad_to(b, dpad, 1)
-    on = _pad_to(on, dpad, 1)
-    amp2 = jnp.exp(2.0 * params.log_amplitude.astype(dt))[:, None]  # (S, 1)
-    return inv_ell, a, b, on, amp2
+    alphas = alphas if alphas.ndim == 3 else alphas[None]
+
+    m, d = x_star.shape
+    n = chol.shape[-1]
+    mpad, tile_a, npad, tile_r = tiling(m, n)
+    dpad = max(8, -(-d // 8) * 8)
+
+    # warped, lengthscale-scaled coordinates per sample, by XLA in the
+    # kernel's dtype; padded features and rows are zero
+    def scaled(x, rows):
+        s = scaled_inputs(x.astype(dt), params)
+        return _pad_to(_pad_to(s, rows, 1), dpad, 2)
+
+    anchors = scaled(x_star, mpad)
+    train_t = jnp.swapaxes(scaled(post.x_train, npad), 1, 2)
+    mask = _pad_to(post.mask.astype(dt)[None, :], npad, 1)
+
+    # identity-extend the (inverted) factor over padded rows; block-diagonal
+    # triangular matrices invert blockwise, so padding and inversion commute.
+    def ident_pad(t):
+        t = _pad_to(_pad_to(t.astype(dt), npad, 1), npad, 2)
+        if npad > n:
+            diag = jnp.arange(n, npad)
+            t = t.at[:, diag, diag].set(1.0)
+        return t
+
+    if post.chol_inv is not None:
+        linv = ident_pad(post.chol_inv if batched else post.chol_inv[None])
+    else:
+        linv = _triangular_inverse(ident_pad(chol))
+    alphasp = _pad_to(alphas.astype(dt), npad, 2)
+    amp2 = exp_accurate(2.0 * params.log_amplitude)[:, None, None]
+
+    out = acq_score_pallas(
+        anchors, train_t, linv, alphasp, mask, amp2,
+        *(jnp.asarray(v, dt) for v in small),
+        mode=mode, num_con=num_con, tile_a=tile_a, tile_r=tile_r,
+        interpret=interpret,
+    )  # (S, mpad)
+    return out[:, :m].astype(x_star.dtype)
 
 
 def acq_score(
@@ -91,54 +125,19 @@ def acq_score(
     if backend != "pallas":
         raise ValueError(f"unknown acq_score backend {backend!r}")
 
-    if interpret is None:
-        interpret = _default_interpret()
-    batched = post.chol.ndim == 3
-    chol = post.chol if batched else post.chol[None]
-    alpha = post.alpha if batched else post.alpha[None]
-    params = (
-        post.params
-        if batched
-        else jax.tree.map(lambda p: p[None], post.params)
+    one = jnp.ones((1, 1))
+    small = (
+        jnp.zeros((1, 1)),  # tcon: no constraints
+        jnp.reshape(y_best, (1, 1)),
+        one,
+        jnp.reshape(kappa, (1, 1)) if acq == "lcb" else one,
+        jnp.zeros((1, 1)),
     )
-
-    m, d = x_star.shape
-    n = chol.shape[-1]
-    npad = max(8, -(-n // 8) * 8)
-    dpad = max(8, -(-d // 8) * 8)
-    tile_a = anchor_tile(-(-m // TILE_A) * TILE_A, npad)
-    mpad = -(-m // tile_a) * tile_a
-    dt = x_star.dtype if interpret else jnp.float32
-
-    anchors = _pad_to(_pad_to(x_star.astype(dt), mpad, 0), dpad, 1)
-    xt = _pad_to(_pad_to(post.x_train.astype(dt), npad, 0), dpad, 1)
-    mask = _pad_to(post.mask.astype(dt)[None, :], npad, 1)
-
-    # identity-extend the (inverted) factor over padded rows; block-diagonal
-    # triangular matrices invert blockwise, so padding and inversion commute.
-    def ident_pad(t):
-        t = _pad_to(_pad_to(t.astype(dt), npad, 1), npad, 2)
-        if npad > n:
-            diag = jnp.arange(n, npad)
-            t = t.at[:, diag, diag].set(1.0)
-        return t
-
-    if post.chol_inv is not None:
-        linv = ident_pad(post.chol_inv if batched else post.chol_inv[None])
-    else:
-        linv = _triangular_inverse(ident_pad(chol))
-    alphap = _pad_to(alpha.astype(dt), npad, 1)
-
-    inv_ell, a, b, on, amp2 = _packed_params_batch(params, dpad, dt)
-    y_b = jnp.asarray(y_best, dt).reshape(1, 1)
-    kap = jnp.asarray(kappa, dt).reshape(1, 1)
-
-    out = acq_score_pallas(
-        anchors, xt, linv, alphap, mask, inv_ell, a, b, on, amp2, y_b, kap,
-        acq=acq, tile_a=tile_a, interpret=interpret,
-    )  # (S, mpad)
-    out = out[:, :m].astype(x_star.dtype)
-    return out if batched else out[0]
+    out = _pallas_scores(
+        post, post.alpha[..., None, :], x_star, acq, 0, small,
+        resolve_interpret(interpret),
+    )
+    return out if post.chol.ndim == 3 else out[0]
 
 
 def acq_score_multi(
@@ -191,64 +190,25 @@ def acq_score_multi(
     if backend != "pallas":
         raise ValueError(f"unknown acq_score backend {backend!r}")
 
-    if interpret is None:
-        interpret = _default_interpret()
-    batched = post.chol.ndim == 3
-    chol = post.chol if batched else post.chol[None]
-    params = (
-        post.params
-        if batched
-        else jax.tree.map(lambda p: p[None], post.params)
-    )
-    alphas = head.alphas  # (S, M, n)
-
-    m, d = x_star.shape
-    n = chol.shape[-1]
-    npad = max(8, -(-n // 8) * 8)
-    dpad = max(8, -(-d // 8) * 8)
-    tile_a = anchor_tile(-(-m // TILE_A) * TILE_A, npad)
-    mpad = -(-m // tile_a) * tile_a
-    dt = x_star.dtype if interpret else jnp.float32
-
-    anchors = _pad_to(_pad_to(x_star.astype(dt), mpad, 0), dpad, 1)
-    xt = _pad_to(_pad_to(post.x_train.astype(dt), npad, 0), dpad, 1)
-    mask = _pad_to(post.mask.astype(dt)[None, :], npad, 1)
-
-    def ident_pad(t):
-        t = _pad_to(_pad_to(t.astype(dt), npad, 1), npad, 2)
-        if npad > n:
-            diag = jnp.arange(n, npad)
-            t = t.at[:, diag, diag].set(1.0)
-        return t
-
-    if post.chol_inv is not None:
-        linv = ident_pad(post.chol_inv if batched else post.chol_inv[None])
-    else:
-        linv = _triangular_inverse(ident_pad(chol))
-    alphasp = _pad_to(alphas.astype(dt), npad, 2)
-
-    inv_ell, a, b, on, amp2 = _packed_params_batch(params, dpad, dt)
-
     num_con = int(head.t_std.shape[0])
-    tcon = head.t_std.astype(dt).reshape(1, -1)
-    if num_con == 0:
-        tcon = jnp.zeros((1, 1), dt)
-    y_b = jnp.asarray(head.y_best, dt).reshape(1, 1)
-    feas = jnp.asarray(head.has_feasible, dt).reshape(1, 1)
-    if mode in ("pareto", "rungs", "cost"):
+    tcon = head.t_std.reshape(-1, 1) if num_con else jnp.zeros((1, 1))
+    if mode == "constrained":
+        weights = ybw = jnp.zeros((1, 1))
+    else:
         # pareto: weights (W, K) draws with ybw (W, 1) scalarized incumbents;
         # rungs: weights (1, M) rung-weight row with ybw (M, 1) per-head
         # incumbents; cost: weights (1, 1) eta with ybw a (1, 1) dummy —
-        # the kernel keys its BlockSpecs off each array's own row count.
-        weights = head.weights.astype(dt)
-        ybw = head.y_best_w.astype(dt).reshape(-1, 1)
-    else:
-        weights = jnp.zeros((1, 1), dt)
-        ybw = jnp.zeros((1, 1), dt)
-
-    out = acq_score_multi_pallas(
-        anchors, xt, linv, alphasp, mask, inv_ell, a, b, on, amp2,
-        tcon, y_b, feas, weights, ybw,
-        mode=mode, num_con=num_con, tile_a=tile_a, interpret=interpret,
-    )  # (S, mpad)
-    return out[:, :m].astype(x_star.dtype)
+        # the kernel takes each small operand whole.
+        weights = head.weights
+        ybw = head.y_best_w.reshape(-1, 1)
+    small = (
+        tcon,
+        jnp.reshape(head.y_best, (1, 1)),
+        jnp.reshape(head.has_feasible, (1, 1)),
+        weights,
+        ybw,
+    )
+    return _pallas_scores(
+        post, head.alphas, x_star, mode, num_con, small,
+        resolve_interpret(interpret),
+    )

@@ -7,13 +7,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.decode_attention.kernel import BC, decode_attention_pallas
 
 __all__ = ["decode_attention"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("softcap", "interpret"))
@@ -26,8 +23,7 @@ def decode_attention(
     softcap: float = 0.0,
     interpret: bool | None = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     b, hq, dh = q.shape
     c, hkv = k_cache.shape[1], k_cache.shape[2]
     g = hq // hkv

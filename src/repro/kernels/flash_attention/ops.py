@@ -15,13 +15,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.kernels.flash_attention.kernel import BK, BQ, _kernel
 
 __all__ = ["flash_attention"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(
@@ -37,8 +34,7 @@ def flash_attention(
     causal: bool = True,
     interpret: bool | None = None,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     b, s, hq, dh = q.shape
     hkv = k.shape[2]
     g = hq // hkv

@@ -7,13 +7,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.mamba_scan.kernel import BD, CS, mamba_scan_pallas
 
 __all__ = ["selective_scan"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -26,8 +23,7 @@ def selective_scan(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Matches ``selective_scan_ref`` semantics: returns y (B, S, di) f32."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     bsz, s, di = u.shape
     ds = a.shape[1]
     spad = -(-s // CS) * CS

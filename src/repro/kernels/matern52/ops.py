@@ -1,33 +1,31 @@
-"""Jitted public wrapper for the Matérn-5/2 Pallas gram kernel.
+"""Jitted public wrappers for the Matérn-5/2 Pallas gram kernel.
 
-Handles padding (rows → TILE multiples; features → lane multiple with
-inv_ell = 0 so padded features are inert), parameter packing, and trimming.
-``interpret=True`` on CPU (this container); on a real TPU fleet pass
-``interpret=False`` (the default flips on TPU platforms).
+The inputs are warped (``repro.core.gp.warping``, which in f32 uses the
+chip-accurate ``exp_accurate`` and ``log_accurate``) and lengthscale-scaled
+here, by XLA in f32 — the reference's own first steps — and padded: rows to
+tile multiples, features to a multiple of 8 with zeros (inert in distances).
+The kernel runs in f32, compiled on a TPU and interpreted on the CPU
+(``repro.kernels.resolve_interpret``; other platforms are refused); the
+wrapper trims the padding.
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.gp.params import GPHyperParams
+from repro.core.gp.warping import warp_inputs
+from repro.kernels import resolve_interpret
 from repro.kernels.matern52.kernel import (
     ROW_TILE,
     TILE_M,
     TILE_N,
-    matern52_cross_pallas,
+    exp_accurate,
     matern52_gram_pallas,
 )
 
-__all__ = ["matern52_gram", "matern52_cross"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+__all__ = ["matern52_gram", "matern52_cross", "scaled_inputs"]
 
 
 def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
@@ -39,24 +37,35 @@ def _pad_to(x: jax.Array, size: int, axis: int) -> jax.Array:
     return jnp.pad(x, widths)
 
 
-def _packed_params(params: GPHyperParams, dpad: int, warp: bool):
-    """(inv_ell, a, b, on, amp2) in the kernel's padded (1, dpad) layout."""
-    inv_ell = _pad_to(
-        jnp.exp(-params.log_lengthscale.astype(jnp.float32))[None, :], dpad, 1
-    )  # padded features: inv_ell = 0 ⇒ inert
-    a = jnp.exp(params.log_warp_a.astype(jnp.float32))[None, :]
-    b = jnp.exp(params.log_warp_b.astype(jnp.float32))[None, :]
-    identity = (
-        (jnp.abs(params.log_warp_a) < 1e-7) & (jnp.abs(params.log_warp_b) < 1e-7)
-    )[None, :]
-    on = jnp.where(identity, 0.0, 1.0).astype(jnp.float32)
-    if not warp:
-        on = jnp.zeros_like(on)
-    a = _pad_to(a, dpad, 1)
-    b = _pad_to(b, dpad, 1)
-    on = _pad_to(on, dpad, 1)
-    amp2 = jnp.exp(2.0 * params.log_amplitude.astype(jnp.float32)).reshape(1, 1)
-    return inv_ell, a, b, on, amp2
+def scaled_inputs(x: jax.Array, params: GPHyperParams, warp: bool = True):
+    """ω(x)/ℓ in x's dtype: the warped (``repro.core.gp.warping``),
+    lengthscale-scaled coordinates whose Euclidean distances the Matérn
+    kernel takes. ``params`` may carry a leading sample axis; the result
+    then has it too."""
+    if warp:
+        x = warp_inputs(x, params.log_warp_a[..., None, :],
+                        params.log_warp_b[..., None, :])
+    return x * exp_accurate(-params.log_lengthscale)[..., None, :]
+
+
+def _gram(x1, x2, params, warp, tile_n, interpret):
+    n, d = x1.shape
+    m = x2.shape[0]
+    npad = -(-n // tile_n) * tile_n
+    mpad = -(-m // TILE_M) * TILE_M
+    dpad = max(8, -(-d // 8) * 8)
+    params = jax.tree.map(lambda p: p.astype(jnp.float32), params)
+
+    def prep(x, rows):
+        s = scaled_inputs(x.astype(jnp.float32), params, warp)
+        return _pad_to(_pad_to(s, rows, 0), dpad, 1)
+
+    amp2 = exp_accurate(2.0 * params.log_amplitude)
+    out = matern52_gram_pallas(
+        prep(x1, npad), prep(x2, mpad).T, amp2.reshape(1, 1),
+        tile_n=tile_n, interpret=resolve_interpret(interpret),
+    )
+    return out[:n, :m].astype(x1.dtype)
 
 
 def matern52_gram(
@@ -68,22 +77,7 @@ def matern52_gram(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Drop-in replacement for ``matern52_ard`` (same semantics/shapes)."""
-    if interpret is None:
-        interpret = _default_interpret()
-    n, d = x1.shape
-    m = x2.shape[0]
-    npad = -(-n // TILE_N) * TILE_N
-    mpad = -(-m // TILE_M) * TILE_M
-    dpad = max(8, -(-d // 8) * 8)
-
-    x1p = _pad_to(_pad_to(x1.astype(jnp.float32), npad, 0), dpad, 1)
-    x2p = _pad_to(_pad_to(x2.astype(jnp.float32), mpad, 0), dpad, 1)
-    inv_ell, a, b, on, amp2 = _packed_params(params, dpad, warp)
-
-    out = matern52_gram_pallas(
-        x1p, x2p, inv_ell, a, b, on, amp2, interpret=interpret
-    )
-    return out[:n, :m].astype(x1.dtype)
+    return _gram(x1, x2, params, warp, TILE_N, interpret)
 
 
 def matern52_cross(
@@ -97,22 +91,7 @@ def matern52_cross(
     """Cross-covariance row k(x_new, X): (d,), (m, d) -> (m,).
 
     The incremental append path (``repro.core.gp.incremental``) calls this
-    once per new observation; only one ROW_TILE × m tile is computed instead
-    of an n×n gram.
+    once per new observation; only one ROW_TILE × m block is computed
+    instead of an n×n gram.
     """
-    if interpret is None:
-        interpret = _default_interpret()
-    (d,) = x_new.shape
-    m = x_train.shape[0]
-    mpad = -(-m // TILE_M) * TILE_M
-    dpad = max(8, -(-d // 8) * 8)
-
-    xn = jnp.broadcast_to(x_new.astype(jnp.float32)[None, :], (ROW_TILE, d))
-    xn = _pad_to(xn, dpad, 1)
-    xt = _pad_to(_pad_to(x_train.astype(jnp.float32), mpad, 0), dpad, 1)
-    inv_ell, a, b, on, amp2 = _packed_params(params, dpad, warp)
-
-    out = matern52_cross_pallas(
-        xn, xt, inv_ell, a, b, on, amp2, interpret=interpret
-    )
-    return out[0, :m].astype(x_train.dtype)
+    return _gram(x_new[None, :], x_train, params, warp, ROW_TILE, interpret)[0]

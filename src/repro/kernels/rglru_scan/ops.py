@@ -7,13 +7,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.rglru_scan.kernel import BD, CS, rglru_scan_pallas
 
 __all__ = ["rglru_scan"]
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -23,8 +20,7 @@ def rglru_scan(
     interpret: bool | None = None,
 ) -> jax.Array:
     """Matches ``rglru_scan_ref``: h (B, S, di) f32."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     bsz, s, di = a.shape
     spad = -(-s // CS) * CS
     dpad = -(-di // BD) * BD

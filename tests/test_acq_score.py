@@ -6,8 +6,8 @@ Three-way triangulation per configuration:
     Pallas kernel (interpret)  vs  gp.predict + acquisition composition
 
 swept over shape buckets, GPHP sample counts, input dims and both closed-form
-acquisitions — tolerance 1e-5 (measured parity is ~1e-12 under the x64 test
-session). Plus end-to-end invariance: a ``BOSuggester`` scoring anchors with
+acquisitions — tolerance 1e-5 (measured parity is ~4e-8 under the x64 test
+session: the kernel's Φ is accurate to f32 rounding, in f64 too). Plus end-to-end invariance: a ``BOSuggester`` scoring anchors with
 ``backend="pallas"`` must pick the same candidates as ``backend="xla"`` on a
 fixed seed, including the ``suggest_batch(k)`` fantasy path.
 """
@@ -162,6 +162,64 @@ def test_cached_inverse_path_matches_recomputed():
         post._replace(chol_inv=None), anchors, y_best, backend="pallas"
     )
     np.testing.assert_allclose(np.asarray(cached), np.asarray(recomputed), atol=1e-10)
+
+
+def test_ndtr_f32_matches_f64_normal_cdf():
+    """The kernel's Φ (rational erf/erfc, Mosaic-lowerable) in f32, the
+    chip's dtype: absolute error at f32 rounding everywhere, and relative
+    accuracy in the lower tail where EI lives far below the incumbent."""
+    import jax
+
+    from repro.kernels.acq_score.kernel import ndtr
+
+    z = np.linspace(-12.0, 8.0, 40001)
+    got = np.asarray(ndtr(jnp.asarray(z, jnp.float32)), np.float64)
+    ref = np.asarray(jax.scipy.special.ndtr(jnp.asarray(z)))
+    assert np.max(np.abs(got - ref)) < 2e-7
+    tail = z < -1.0
+    rel = np.abs(got[tail] - ref[tail]) / ref[tail]
+    # exp(−z²/2) carries the f32 rounding of z² in its exponent, as XLA's
+    # own f32 erfc does: relative error ~ ε·z² (z = −12 is Φ ~ 1e-33)
+    assert np.all(rel < 1e-6 + 2e-7 * z[tail] ** 2)
+
+
+@pytest.mark.parametrize("acq", ["ei", "lcb"])
+def test_row_tiled_factor_f32_parity(monkeypatch, acq):
+    """f32 interpret mode (the chip's arithmetic) with the inverse factor
+    streamed in 8-row blocks over 8 grid steps and two anchor tiles must
+    match the one-block f32 run up to summation order, and the f64 oracle
+    to f32 accuracy on this posterior (|α| ~ 2e3, so K*·α carries ~1e-3)."""
+    import jax
+
+    from repro.kernels.acq_score import kernel as K
+
+    post, anchors, y_best = _posterior(64, 50, 4, S=3, seed=5)
+    post = post._replace(chol_inv=G._triangular_inverse(post.chol))
+    post32 = jax.tree.map(
+        lambda a: a.astype(jnp.float32) if a.dtype == jnp.float64 else a, post
+    )
+    args = (post32, anchors.astype(jnp.float32), y_best.astype(jnp.float32))
+    whole = acq_score(*args, acq=acq, backend="pallas")
+    monkeypatch.setattr(K, "_VMEM_TILE_ELEMS", 512)
+    assert K.tiling(200, 64) == (256, 128, 64, 8)
+    tiled = acq_score(*args, acq=acq, backend="pallas")
+    assert tiled.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(tiled), np.asarray(whole), atol=5e-5)
+    ref = acq_score_ref(post, anchors, y_best, acq=acq)
+    np.testing.assert_allclose(np.asarray(tiled), np.asarray(ref), atol=2e-3)
+
+
+def test_tiling_bounds_vmem_blocks():
+    """Every bucket gets row and anchor tiles that divide the padded sizes
+    and keep each VMEM block within about the tile budget."""
+    from repro.kernels.acq_score.kernel import _VMEM_TILE_ELEMS, tiling
+
+    for n in (5, 200, 512, 1500, 2048, 4096, 8192, 16384):
+        mpad, tile_a, npad, tile_r = tiling(1024, n)
+        assert npad >= n and npad % tile_r == 0 and tile_r % 8 == 0
+        assert mpad >= 1024 and mpad % tile_a == 0 and tile_a % 128 == 0
+        assert tile_r * npad <= 2 * _VMEM_TILE_ELEMS
+        assert tile_a * npad <= 2 * _VMEM_TILE_ELEMS or tile_a == 128
 
 
 def test_rejects_unsupported():

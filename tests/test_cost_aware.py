@@ -165,7 +165,7 @@ def _cost_head(alphas, y_best, eta):
 @pytest.mark.parametrize("d", [2, 12])
 def test_cost_mode_kernel_parity(n, s, d):
     """pallas vs ref vs xla on mode="cost" (acceptance 1e-5; measured
-    ~1e-12 in f64 interpret mode)."""
+    ~1e-8 in f64 interpret mode)."""
     post, alphas, y_best, rng = _cost_posterior(11 * n + s + d, n, s, d)
     xs = jnp.asarray(rng.random((300, d)))
     head = _cost_head(alphas, y_best, eta=1.7)

@@ -75,6 +75,36 @@ def test_matern52_cross_sweep(m, d, warp):
     np.testing.assert_allclose(got, want, atol=2e-5)
 
 
+@pytest.mark.parametrize("dtype,rel", [(jnp.float32, 4e-7), (jnp.float64, 1e-15)])
+def test_exp_accurate(dtype, rel):
+    """The kernels' eˣ (mul/add + exponent bitcast, because Mosaic's f32 exp
+    is ~4e-6 off on a v5e) keeps the rounding of its dtype over the range
+    the Matérn response, EI and Φ's tail use, and clamps below at e^-87."""
+    from repro.kernels.matern52.kernel import exp_accurate
+
+    x = np.concatenate([np.linspace(-87.0, 88.0, 200001), [0.0, -1e-9, 1e-9]])
+    got = np.asarray(exp_accurate(jnp.asarray(x, dtype)), np.float64)
+    want = np.exp(np.asarray(x, dtype).astype(np.float64))
+    assert np.max(np.abs(got - want) / want) < rel
+    assert float(exp_accurate(jnp.asarray(-200.0, dtype))) == pytest.approx(
+        np.exp(-87.0), rel=1e-6
+    )
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2.5e-7), (jnp.float64, 5e-16)])
+def test_log_accurate(dtype, tol):
+    """The wrappers' ln x (frexp + atanh series, because XLA's f32 log is
+    ~3.5e-4 off on a v5e) keeps the rounding of its dtype, relative to
+    max(|ln x|, 1), over the warp's inputs [1e-6, 1] and beyond."""
+    from repro.kernels.matern52.kernel import log_accurate
+
+    x = np.concatenate([np.geomspace(1e-7, 1e3, 100001), np.linspace(0.5, 2, 10001)])
+    x = np.asarray(x, dtype)
+    got = np.asarray(log_accurate(jnp.asarray(x)), np.float64)
+    want = np.log(x.astype(np.float64))
+    assert np.max(np.abs(got - want) / np.maximum(np.abs(want), 1.0)) < tol
+
+
 # ----------------------------------------------------------- flash attention
 @pytest.mark.parametrize(
     "b,s,hq,hkv,dh,window,softcap",

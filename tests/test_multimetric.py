@@ -679,7 +679,7 @@ def _multi_posterior(seed, n, s, d, m_heads):
 def test_multi_head_kernel_parity_sweep(n, s, d, mode):
     """Fused multi-head scorer vs the standalone jnp oracle vs the
     production composition, across shape buckets / samples / dims / modes
-    (acceptance bound 1e-5; measured ~1e-12 in f64 interpret mode)."""
+    (acceptance bound 1e-5; measured ~1e-8 in f64 interpret mode)."""
     from repro.core.optimize_acq import MultiMetricHead
     from repro.kernels.acq_score.ops import acq_score_multi
     from repro.kernels.acq_score.ref import acq_score_multi_ref

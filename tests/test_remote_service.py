@@ -4,6 +4,7 @@ as the in-process service, exact), replica-crash failover via lease expiry,
 and the wire protocol's refusal paths (protocol/snapshot version mismatch,
 expired/held leases, stale state)."""
 
+import dataclasses
 import json
 import math
 import os
@@ -225,8 +226,9 @@ class TestSnapshotRoundTrip:
 
 class TestConfigWire:
     def test_bo_config_roundtrip(self):
-        blob = json.loads(json.dumps(bo_config_to_wire(_CFG)))
-        assert bo_config_from_wire(blob) == _CFG
+        for cfg in (_CFG, dataclasses.replace(_CFG, fit_on_host=False)):
+            blob = json.loads(json.dumps(bo_config_to_wire(cfg)))
+            assert bo_config_from_wire(blob) == cfg
 
 
 # ------------------------------------------------------------------- socket

@@ -72,3 +72,51 @@ def test_map_beats_init():
     assert float(G.log_posterior_density(x, y, best, bounds, mask)) > float(
         G.log_posterior_density(x, y, z0, bounds, mask)
     )
+
+
+def test_fit_runs_on_host_when_tpu_is_default(monkeypatch):
+    """``BOConfig.fit_on_host`` (the default) runs the GPHP fit on the host
+    CPU device whatever JAX's default backend is, and hands back the same
+    bits as a fit on the default device, uncommitted, on the caller's
+    default device. ``fit_backend="pallas"`` is refused on the host rather
+    than interpreted."""
+    import dataclasses
+
+    import pytest
+
+    from repro.core import BOConfig, BOSuggester, Continuous, SearchSpace
+    from repro.core import suggest as S
+
+    host = jax.devices("cpu")[0]
+    placed = []
+
+    def spy(fit):
+        def run(*args):
+            placed.append(jax.config.jax_default_device)
+            return fit(*args)
+
+        return run
+
+    monkeypatch.setattr(S, "mcmc_gphps", spy(mcmc_gphps))
+    monkeypatch.setattr(S, "map_gphps", spy(map_gphps))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    space = SearchSpace([Continuous("a", 0.0, 1.0), Continuous("b", 0.0, 1.0)])
+    rng = np.random.default_rng(3)
+    n = 12
+    data = (jnp.asarray(rng.random((n, 2))), jnp.asarray(rng.standard_normal(n)),
+            jnp.ones(n, bool))
+    small = SliceSamplerConfig(num_samples=20, burn_in=10, thin=2)
+    for method in ("mcmc", "map"):
+        on_host = BOConfig(gphp_method=method, slice_config=small)
+        assert on_host.fit_on_host
+        on_dev = dataclasses.replace(on_host, fit_on_host=False)
+        placed.clear()
+        got = BOSuggester(space, on_host, seed=0)._fit_gphps(*data)
+        want = BOSuggester(space, on_dev, seed=0)._fit_gphps(*data)
+        assert placed == [host, None]
+        assert not got.committed
+        assert got.devices() == {jax.devices()[0]}
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    with pytest.raises(ValueError, match="fit_on_host"):
+        BOConfig(fit_backend="pallas")
+    assert BOConfig(fit_backend="pallas", fit_on_host=False).fit_backend == "pallas"
