@@ -91,6 +91,18 @@ def _acq_values(
     return A.integrate_over_samples(vals)
 
 
+def _stage(name: str, fn, *args):
+    """``fn(*args)`` as a nested jitted call under the named scope ``name``.
+
+    XLA inlines the call, so the compiled program is the same. The call is
+    what carries the scope into every instruction's ``op_name`` when JAX
+    writes locations without full tracebacks, as the entry points that use
+    the persistent cache do (``repro.compile_cache``): a scope alone then
+    survives only on instructions that are themselves calls."""
+    with jax.named_scope(name):
+        return jax.jit(fn)(*args)
+
+
 def _refine_and_rank(
     masked_acq,
     anchors: jax.Array,
@@ -98,10 +110,17 @@ def _refine_and_rank(
 ) -> tuple[jax.Array, jax.Array]:
     """Shared stage 2–4 of the pipeline: top-k anchors → projected-Adam
     ascent on the (masked) acquisition → re-rank. ``masked_acq(x,
-    differentiable)`` scores (m, d) → (m,), larger is better."""
-    anchor_vals = masked_acq(anchors)  # (num_anchors,)
-    top_idx = jax.lax.top_k(anchor_vals, cfg.num_refine)[1]
-    x0 = anchors[top_idx]  # (num_refine, d)
+    differentiable)`` scores (m, d) → (m,), larger is better.
+
+    The stages run as ``acq.anchors``, ``acq.refine`` and ``acq.rerank``
+    (``_stage``), so a device trace can be split by stage."""
+
+    def top_anchors(anchors):
+        anchor_vals = masked_acq(anchors)  # (num_anchors,)
+        top_idx = jax.lax.top_k(anchor_vals, cfg.num_refine)[1]
+        return anchors[top_idx], anchor_vals[top_idx]  # (num_refine, ·)
+
+    x0, x0_vals = _stage("acq.anchors", top_anchors, anchors)
 
     # --- projected Adam ascent on the acquisition -------------------------
     # (differentiable=True: refinement keeps the XLA path for jax.grad)
@@ -121,21 +140,29 @@ def _refine_and_rank(
         x = jnp.clip(x + cfg.refine_lr * mhat / (jnp.sqrt(vhat) + 1e-8), 0.0, 1.0)
         return (x, m, v, t + 1.0), None
 
-    (x_ref, _, _, _), _ = jax.lax.scan(
-        step,
-        (x0, jnp.zeros_like(x0), jnp.zeros_like(x0), jnp.asarray(0.0)),
-        None,
-        length=cfg.refine_steps,
-    )
+    def refine(x0):
+        (x_ref, _, _, _), _ = jax.lax.scan(
+            step,
+            (x0, jnp.zeros_like(x0), jnp.zeros_like(x0), jnp.asarray(0.0)),
+            None,
+            length=cfg.refine_steps,
+        )
+        return x_ref
 
-    ref_vals = masked_acq(x_ref)
-    # A refined point may have walked into the exclusion zone; keep the anchor
-    # value as fallback so ranking never returns −inf when anchors were valid.
-    use_ref = ref_vals >= anchor_vals[top_idx]
-    final_x = jnp.where(use_ref[:, None], x_ref, x0)
-    final_v = jnp.where(use_ref, ref_vals, anchor_vals[top_idx])
-    order = jnp.argsort(-final_v)
-    return final_x[order], final_v[order]
+    x_ref = _stage("acq.refine", refine, x0)
+
+    def rerank(x_ref, x0, x0_vals):
+        ref_vals = masked_acq(x_ref)
+        # A refined point may have walked into the exclusion zone; keep the
+        # anchor value as fallback so ranking never returns −inf when anchors
+        # were valid.
+        use_ref = ref_vals >= x0_vals
+        final_x = jnp.where(use_ref[:, None], x_ref, x0)
+        final_v = jnp.where(use_ref, ref_vals, x0_vals)
+        order = jnp.argsort(-final_v)
+        return final_x[order], final_v[order]
+
+    return _stage("acq.rerank", rerank, x_ref, x0, x0_vals)
 
 
 def _pending_masked(score, pending: jax.Array, pending_mask: jax.Array,

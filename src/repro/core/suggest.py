@@ -600,6 +600,72 @@ class BOSuggester:
         return self._decide(self._store, k, pend_np)
 
     # ------------------------------------------------------------ decisions
+    def _decision_posterior(
+        self, store: ObservationStore, x_all: np.ndarray, y_std: np.ndarray
+    ):
+        """The decision's posterior: the cached factor (refit, adopted or
+        appended as the cadence says) with alpha refreshed on the live rows'
+        standardized targets ``y_std``. Returns (post, live rows in store
+        order, the padded targets). The span covers the dispatch of the
+        refresh, not its device work."""
+        n = store.num_observations
+        with telemetry.span("suggest.posterior", n=n):
+            post = self._posterior_for(store, x_all, y_std)
+            rows = self.cache.live_rows(n)  # factor rows, in store order
+            y_live = np.zeros(post.x_train.shape[0])
+            y_live[: len(rows)] = y_std[rows]
+            post = refresh_alpha(post, jnp.asarray(y_live))
+        self.cache.post = post
+        return post, rows, y_live
+
+    def _pick(
+        self, slot, work, pend_buf, pend_mask, x_all, pend_np, picks, *,
+        y_best=None, head=None, spec=None,
+    ):
+        """One slot of a batched refill: optimize the acquisition (the
+        multi-head program when ``spec`` is given), read its candidates to
+        the host, and return (config, encoded vector) of the best that
+        round-trips to a point not yet seen, else a quasi-random one.
+
+        ``suggest.acq_opt`` ends once the candidates are on the host, so it
+        holds the device's work; ``suggest.dedup`` is the host loop."""
+        cfg = self.config
+        space = self.space
+        with telemetry.span(
+            "suggest.acq_opt", backend=cfg.acq.backend, slot=slot
+        ):
+            if spec is None:
+                cands, _ = optimize_acquisition(
+                    work,
+                    self._anchors,
+                    y_best,
+                    jnp.asarray(pend_buf),
+                    jnp.asarray(pend_mask),
+                    self._next_key(),
+                    cfg.acq,
+                )
+            else:
+                cands, _ = optimize_acquisition_multi(
+                    work,
+                    head,
+                    self._anchors,
+                    jnp.asarray(pend_buf),
+                    jnp.asarray(pend_mask),
+                    self._next_key(),
+                    cfg.acq,
+                    spec,
+                )
+            cands = np.asarray(cands)
+        with telemetry.span("suggest.dedup", slot=slot):
+            seen = self._seen_matrix(x_all, pend_np, picks)
+            for cand in cands:
+                snapped = space.round_trip(cand)
+                if len(seen) == 0 or np.min(
+                    np.max(np.abs(seen - snapped[None, :]), axis=1)
+                ) > cfg.dedupe_tol:
+                    return space.decode(snapped), snapped
+            return self._quasi_random(seen)
+
     def _decide(
         self, store: ObservationStore, k: int, pend_np: np.ndarray
     ) -> List[Dict[str, Any]]:
@@ -658,15 +724,8 @@ class BOSuggester:
                 return self._decide_cost(store, k, pend_np, costs)
 
         x_all, y_std, _, _ = store.standardized()
-        with telemetry.span("suggest.posterior", n=n):
-            post = self._posterior_for(store, x_all, y_std)
-        rows = self.cache.live_rows(n)  # factor rows, in store order
+        post, rows, y_live = self._decision_posterior(store, x_all, y_std)
         n_live = len(rows)
-        size = post.x_train.shape[0]
-        y_live = np.zeros(size)
-        y_live[:n_live] = y_std[rows]
-        post = refresh_alpha(post, jnp.asarray(y_live))
-        self.cache.post = post
         y_best = jnp.asarray(float(y_std.min()))  # best *real* observation
 
         # --- pending (§4.4) + scratch posterior for fantasies ---------------
@@ -696,30 +755,10 @@ class BOSuggester:
 
         # --- batched refill: one pipeline pass fills all k slots -------------
         for slot in range(k):
-            with telemetry.span(
-                "suggest.acq_opt", backend=cfg.acq.backend, slot=slot
-            ):
-                cands, _ = optimize_acquisition(
-                    work,
-                    self._anchors,
-                    y_best,
-                    jnp.asarray(pend_buf),
-                    jnp.asarray(pend_mask),
-                    self._next_key(),
-                    cfg.acq,
-                )
-            with telemetry.span("suggest.dedup", slot=slot):
-                seen = self._seen_matrix(x_all, pend_np, picks)
-                config = vec = None
-                for cand in np.asarray(cands):
-                    snapped = space.round_trip(cand)
-                    if len(seen) == 0 or np.min(
-                        np.max(np.abs(seen - snapped[None, :]), axis=1)
-                    ) > cfg.dedupe_tol:
-                        config, vec = space.decode(snapped), snapped
-                        break
-                if config is None:
-                    config, vec = self._quasi_random(seen)
+            config, vec = self._pick(
+                slot, work, pend_buf, pend_mask, x_all, pend_np, picks,
+                y_best=y_best,
+            )
             out.append(config)
             picks.append(vec)
             if slot + 1 < k:
@@ -759,17 +798,11 @@ class BOSuggester:
         num_obj = ms.num_objectives
 
         x_all, ystd, means, scales = store.standardized_metrics()
-        with telemetry.span("suggest.posterior", n=n):
-            post = self._posterior_for(
-                store, x_all, np.ascontiguousarray(ystd[:, 0])
-            )
-        rows = self.cache.live_rows(n)  # factor rows, in store order
+        post, rows, _ = self._decision_posterior(
+            store, x_all, np.ascontiguousarray(ystd[:, 0])
+        )
         n_live = len(rows)
         size = post.x_train.shape[0]
-        y_live = np.zeros(size)
-        y_live[:n_live] = ystd[rows, 0]
-        post = refresh_alpha(post, jnp.asarray(y_live))
-        self.cache.post = post
 
         y_heads = np.zeros((m_all, size))
         y_heads[:, :n_live] = ystd[rows].T
@@ -863,31 +896,10 @@ class BOSuggester:
         picks: List[np.ndarray] = []
         out: List[Dict[str, Any]] = []
         for slot in range(k):
-            with telemetry.span(
-                "suggest.acq_opt", backend=cfg.acq.backend, slot=slot
-            ):
-                cands, _ = optimize_acquisition_multi(
-                    work,
-                    head,
-                    self._anchors,
-                    jnp.asarray(pend_buf),
-                    jnp.asarray(pend_mask),
-                    self._next_key(),
-                    cfg.acq,
-                    spec,
-                )
-            with telemetry.span("suggest.dedup", slot=slot):
-                seen = self._seen_matrix(x_all, pend_np, picks)
-                config = vec = None
-                for cand in np.asarray(cands):
-                    snapped = space.round_trip(cand)
-                    if len(seen) == 0 or np.min(
-                        np.max(np.abs(seen - snapped[None, :]), axis=1)
-                    ) > cfg.dedupe_tol:
-                        config, vec = space.decode(snapped), snapped
-                        break
-                if config is None:
-                    config, vec = self._quasi_random(seen)
+            config, vec = self._pick(
+                slot, work, pend_buf, pend_mask, x_all, pend_np, picks,
+                head=head, spec=spec,
+            )
             out.append(config)
             picks.append(vec)
             if slot + 1 < k:
@@ -936,15 +948,9 @@ class BOSuggester:
         m_all = 1 + num_rungs
 
         x_all, y_std, _, _ = store.standardized()
-        with telemetry.span("suggest.posterior", n=n):
-            post = self._posterior_for(store, x_all, y_std)
-        rows = self.cache.live_rows(n)  # factor rows, in store order
+        post, rows, _ = self._decision_posterior(store, x_all, y_std)
         n_live = len(rows)
         size = post.x_train.shape[0]
-        y_live = np.zeros(size)
-        y_live[:n_live] = y_std[rows]
-        post = refresh_alpha(post, jnp.asarray(y_live))
-        self.cache.post = post
 
         # (R, n) standardized rung-head targets; rows without a rung-k value
         # impute their final objective (dense columns — no per-head masks).
@@ -1004,31 +1010,10 @@ class BOSuggester:
         picks: List[np.ndarray] = []
         out: List[Dict[str, Any]] = []
         for slot in range(k):
-            with telemetry.span(
-                "suggest.acq_opt", backend=cfg.acq.backend, slot=slot
-            ):
-                cands, _ = optimize_acquisition_multi(
-                    work,
-                    head,
-                    self._anchors,
-                    jnp.asarray(pend_buf),
-                    jnp.asarray(pend_mask),
-                    self._next_key(),
-                    cfg.acq,
-                    spec,
-                )
-            with telemetry.span("suggest.dedup", slot=slot):
-                seen = self._seen_matrix(x_all, pend_np, picks)
-                config = vec = None
-                for cand in np.asarray(cands):
-                    snapped = space.round_trip(cand)
-                    if len(seen) == 0 or np.min(
-                        np.max(np.abs(seen - snapped[None, :]), axis=1)
-                    ) > cfg.dedupe_tol:
-                        config, vec = space.decode(snapped), snapped
-                        break
-                if config is None:
-                    config, vec = self._quasi_random(seen)
+            config, vec = self._pick(
+                slot, work, pend_buf, pend_mask, x_all, pend_np, picks,
+                head=head, spec=spec,
+            )
             out.append(config)
             picks.append(vec)
             if slot + 1 < k:
@@ -1084,15 +1069,9 @@ class BOSuggester:
         m_all = 2  # objective head + log-cost head
 
         x_all, y_std, _, _ = store.standardized()
-        with telemetry.span("suggest.posterior", n=n):
-            post = self._posterior_for(store, x_all, y_std)
-        rows = self.cache.live_rows(n)  # factor rows, in store order
+        post, rows, _ = self._decision_posterior(store, x_all, y_std)
         n_live = len(rows)
         size = post.x_train.shape[0]
-        y_live = np.zeros(size)
-        y_live[:n_live] = y_std[rows]
-        post = refresh_alpha(post, jnp.asarray(y_live))
-        self.cache.post = post
 
         # standardized log-cost targets over the full store prefix
         zc = np.zeros(n)
@@ -1167,31 +1146,10 @@ class BOSuggester:
         picks: List[np.ndarray] = []
         out: List[Dict[str, Any]] = []
         for slot in range(k):
-            with telemetry.span(
-                "suggest.acq_opt", backend=cfg.acq.backend, slot=slot
-            ):
-                cands, _ = optimize_acquisition_multi(
-                    work,
-                    head,
-                    self._anchors,
-                    jnp.asarray(pend_buf),
-                    jnp.asarray(pend_mask),
-                    self._next_key(),
-                    cfg.acq,
-                    spec,
-                )
-            with telemetry.span("suggest.dedup", slot=slot):
-                seen = self._seen_matrix(x_all, pend_np, picks)
-                config = vec = None
-                for cand in np.asarray(cands):
-                    snapped = space.round_trip(cand)
-                    if len(seen) == 0 or np.min(
-                        np.max(np.abs(seen - snapped[None, :]), axis=1)
-                    ) > cfg.dedupe_tol:
-                        config, vec = space.decode(snapped), snapped
-                        break
-                if config is None:
-                    config, vec = self._quasi_random(seen)
+            config, vec = self._pick(
+                slot, work, pend_buf, pend_mask, x_all, pend_np, picks,
+                head=head, spec=spec,
+            )
             out.append(config)
             picks.append(vec)
             if slot + 1 < k:

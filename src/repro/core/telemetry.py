@@ -44,6 +44,25 @@ breakdown and job timeline from the JSONL.
 The clock is injectable (tests use a fake); the default is
 ``time.monotonic`` — host-monotonic is fine here precisely because none of
 this ever feeds back into the engine.
+
+Profiler mirror and compile events
+----------------------------------
+
+While a registry is enabled, each live span also opens a
+``jax.profiler.TraceAnnotation`` of the span's name (no attributes) for
+its duration, on the same thread and nested the same way, so a profiler
+trace shows the engine's phases on the device trace's clock. It costs
+about a microsecond per span while no profiler trace is being taken.
+``jax`` is imported on the first live span, so this module imports without
+it (and then mirrors nothing).
+
+The first enable of any registry registers, once per process, a
+``jax.monitoring`` listener for backend compiles. On each compile it
+writes to the registry that is current *then* (``get()``): the counter
+``jax.compiles`` and the point event ``jax.compile`` (attrs: ``secs``,
+``fun``) whose ``parent_id`` is the span open on the compiling thread.
+JAX reports a program loaded from the persistent compilation cache through
+the same event, with the load's seconds.
 """
 
 from __future__ import annotations
@@ -132,6 +151,50 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: ``jax.profiler.TraceAnnotation``, or a no-op where jax is missing;
+#: resolved on the first live span.
+_annotation: Optional[Callable[[str], Any]] = None
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_lock = threading.Lock()
+_compile_listener_registered = False
+
+
+def _annotate(name: str):
+    """A profiler annotation named ``name`` (entered by the caller)."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:  # telemetry without jax: nothing to mirror into
+            TraceAnnotation = lambda _name: _NULL_SPAN  # noqa: E731
+        _annotation = TraceAnnotation
+    return _annotation(name)
+
+
+def _on_compile(event: str, secs: float, **kwargs: Any) -> None:
+    if event != _COMPILE_EVENT:
+        return
+    registry = get()  # the registry current at compile time
+    if registry.enabled:
+        registry.count("jax.compiles")
+        registry.event("jax.compile", secs=secs, fun=kwargs.get("fun_name"))
+
+
+def _register_compile_listener() -> None:
+    """Register ``_on_compile`` once per process. It stays registered and
+    writes only while the registry current at the compile is enabled."""
+    global _compile_listener_registered
+    with _compile_listener_lock:
+        if _compile_listener_registered:
+            return
+        try:
+            import jax.monitoring
+        except ImportError:
+            return
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _compile_listener_registered = True
+
 
 class Telemetry:
     """Monotonic counters + gauges + log-bucket histograms + span tracing.
@@ -155,6 +218,8 @@ class Telemetry:
         self._trace: deque = deque(maxlen=int(trace_capacity))
         self._ids = itertools.count(1)
         self._stack = threading.local()
+        if self._enabled:
+            _register_compile_listener()
 
     # ------------------------------------------------------------- control
 
@@ -164,6 +229,8 @@ class Telemetry:
 
     def set_enabled(self, on: bool) -> None:
         self._enabled = bool(on)
+        if self._enabled:
+            _register_compile_listener()
 
     def reset(self) -> None:
         """Drop every counter, gauge, histogram, and trace event."""
@@ -221,7 +288,8 @@ class Telemetry:
         """Context manager timing a phase; nests via a thread-local stack.
 
         On exit the span lands in the trace ring (with its parent edge) and
-        its duration feeds the ``span.<name>`` histogram. While disabled, a
+        its duration feeds the ``span.<name>`` histogram; for its duration
+        it is also a profiler annotation of the same name. While disabled, a
         shared no-op context manager is returned so call sites stay cheap.
         """
         if not self._enabled:
@@ -235,11 +303,14 @@ class Telemetry:
         parent_id = self._parent_id()
         stack = self._ensure_stack()
         stack.append(span_id)
+        annotation = _annotate(name)
+        annotation.__enter__()
         t0 = self._clock()
         try:
             yield
         finally:
             t1 = self._clock()
+            annotation.__exit__(None, None, None)
             stack.pop()
             with self._lock:
                 self._trace.append({

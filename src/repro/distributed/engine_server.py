@@ -225,7 +225,12 @@ class EngineServer:
         verb = getattr(msg, "TYPE", "unknown")
         with telemetry.span("rpc." + verb):
             try:
-                with self._lock, self._on_device():
+                with contextlib.ExitStack() as held:
+                    # the wait for the replica, a sibling of the dispatch's
+                    # spans: rpc.<verb> less this span is the lock's hold
+                    with telemetry.span("server.lock_wait", verb=verb):
+                        held.enter_context(self._lock)
+                    held.enter_context(self._on_device())
                     reply = self._dispatch(msg)
             except ProtocolError as e:
                 reply = ErrorReply(code=e.code, message=e.message,

@@ -505,3 +505,208 @@ class TestClientObservability:
             rh.close()
         finally:
             s2.shutdown()
+
+
+# ------------------------------------------------- the profiler's clock
+
+
+def _host_events(logdir):
+    """Each ``/host:CPU`` line of the profiler trace under ``logdir``:
+    [(name, start_ns, end_ns)]."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(f"{logdir}/**/*.xplane.pb", recursive=True)
+    data = ProfileData.from_file(path)
+    return [
+        [(e.name, int(e.start_ns), int(e.start_ns + e.duration_ns))
+         for e in line.events]
+        for plane in data.planes if plane.name == "/host:CPU"
+        for line in plane.lines
+    ]
+
+
+class TestProfilerClock:
+    def test_spans_mirror_into_the_profiler_trace(self, tmp_path):
+        """A live span is a profiler annotation of its name (attributes
+        left out), nested as the spans nest; a span recorded while off
+        is not."""
+        import time
+
+        import jax
+
+        t = Telemetry(enabled=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+        try:
+            with t.span("mirror.outer", job="j"):
+                with t.span("mirror.inner", slot=0):
+                    time.sleep(0.002)
+            t.set_enabled(False)
+            with t.span("mirror.off"):
+                time.sleep(0.002)
+        finally:
+            jax.profiler.stop_trace()
+        lines = _host_events(tmp_path)
+        (line,) = [ln for ln in lines
+                   if any(n == "mirror.outer" for n, _, _ in ln)]
+        outer = next(e for e in line if e[0] == "mirror.outer")
+        inner = next(e for e in line if e[0] == "mirror.inner")
+        assert outer[1] <= inner[1] and inner[2] <= outer[2]
+        assert inner[2] - inner[1] >= 2_000_000
+        names = {n for ln in lines for n, _, _ in ln}
+        assert "mirror.off" not in names
+        assert not any("job=" in n or "slot=" in n for n in names)
+
+    def test_acq_opt_holds_the_read_of_the_candidates(self, monkeypatch):
+        """``suggest.acq_opt`` ends once the candidates are on the host: a
+        slow read of them lands in it, not in ``suggest.dedup``."""
+        import time
+
+        import numpy as np
+
+        import repro.core.suggest as suggest_mod
+
+        real = suggest_mod.optimize_acquisition
+
+        class Slow:
+            def __init__(self, arr):
+                self.arr = arr
+
+            def __array__(self, dtype=None, copy=None):
+                time.sleep(0.05)
+                return np.asarray(self.arr, dtype=dtype)
+
+        def slow_acq(*args):
+            cands, vals = real(*args)
+            return Slow(cands), vals
+
+        h = SelectionService(ServiceConfig()).register_job(
+            "job", _space(), bo_config=_CFG, seed=7)
+        _drive(h, 4)  # past the cold start, programs compiled
+        monkeypatch.setattr(suggest_mod, "optimize_acquisition", slow_acq)
+        telemetry.set_enabled(True)
+        _drive(h, 1, start=4)
+        events = telemetry.get().trace_events()
+        (acq,) = [e for e in events if e["name"] == "suggest.acq_opt"]
+        (dedup,) = [e for e in events if e["name"] == "suggest.dedup"]
+        assert acq["parent_id"] == dedup["parent_id"]
+        assert acq["dur"] >= 0.05 > dedup["dur"]
+
+    def test_lock_wait_is_a_sibling_of_the_decision(self):
+        """``server.lock_wait`` is a child of ``rpc.<verb>`` that closes
+        before the dispatch's spans open; with two clients contending and
+        the lock held from outside for 100 ms, the wait is measured."""
+        import time
+
+        from repro.distributed.engine_client import RemoteService
+        from repro.distributed.engine_server import EngineServer
+
+        telemetry.set_enabled(True)
+        with EngineServer() as server:
+            handles = [
+                RemoteService([server.address]).register_job(
+                    f"job-{i}", _space(), bo_config=_CFG, seed=i)
+                for i in range(2)
+            ]
+            for h in handles:
+                _drive(h, 4)
+            telemetry.get().reset()
+            results = []
+            threads = [
+                threading.Thread(
+                    target=lambda h=h: results.append(_drive(h, 2, start=4)))
+                for h in handles
+            ]
+            with server._lock:
+                for th in threads:
+                    th.start()
+                time.sleep(0.1)
+            for th in threads:
+                th.join()
+            for h in handles:
+                h.close()
+        assert len(results) == 2
+        events = telemetry.get().trace_events()
+        by_id = {e["span_id"]: e for e in events}
+        rpcs = [e for e in events if e["name"] == "rpc.suggest_batch"]
+        assert len(rpcs) == 4
+        waits = [e for e in events if e["name"] == "server.lock_wait"
+                 and by_id[e["parent_id"]]["name"] == "rpc.suggest_batch"]
+        assert sorted(w["parent_id"] for w in waits) == sorted(
+            r["span_id"] for r in rpcs)
+        assert all(w["attrs"] == {"verb": "suggest_batch"} for w in waits)
+        service = [e for e in events if e["name"] == "service.suggest_batch"]
+        assert len(service) == 4
+        for s in service:
+            rpc = by_id[s["parent_id"]]
+            assert rpc["name"] == "rpc.suggest_batch"
+            (wait,) = [w for w in waits if w["parent_id"] == rpc["span_id"]]
+            assert wait["t1"] <= s["t0"]
+        assert max(w["dur"] for w in waits) >= 0.09
+
+    def test_compile_is_counted_under_the_open_span(self):
+        """A compile inside a span counts ``jax.compiles`` and records a
+        ``jax.compile`` event parented to that span, in the registry that
+        is current at the compile, after ``_GLOBAL`` was swapped."""
+        import jax
+        import jax.numpy as jnp
+
+        telemetry.set_enabled(True)  # the first enable registers
+        prior = telemetry._GLOBAL
+        swapped = Telemetry(enabled=True)
+        telemetry._GLOBAL = swapped
+        try:
+            fresh = jax.jit(lambda x: jnp.sin(x) * 3.25 + 0.5)
+            with swapped.span("decide"):
+                fresh(jnp.arange(5.0)).block_until_ready()
+        finally:
+            telemetry._GLOBAL = prior
+        events = swapped.trace_events()
+        (decide,) = [e for e in events if e["name"] == "decide"]
+        compiles = [e for e in events if e["name"] == "jax.compile"]
+        assert swapped.metrics()["counters"]["jax.compiles"] == len(compiles)
+        assert any(e["parent_id"] == decide["span_id"] for e in compiles)
+        assert all(e["attrs"]["secs"] >= 0.0 for e in compiles)
+        assert "jax.compiles" not in prior.metrics()["counters"]
+
+    @pytest.mark.parametrize("full_tracebacks", [True, False])
+    def test_acquisition_stages_named_in_the_compiled_program(
+            self, full_tracebacks):
+        """Every stage of the acquisition program carries its scope into
+        the compiled instructions' ``op_name``, also when locations are
+        written without full tracebacks, as the entry points that use the
+        persistent compilation cache write them."""
+        import re
+
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core.gp.gp import GPPosterior
+        from repro.core.gp.params import GPHyperParams
+        from repro.core.optimize_acq import AcqOptConfig, optimize_acquisition
+
+        s, n, d = 2, 8, 2
+        params = GPHyperParams(jnp.zeros((s, d)), jnp.zeros(s), jnp.zeros(s),
+                               jnp.zeros((s, d)), jnp.zeros((s, d)))
+        eye = jnp.broadcast_to(jnp.eye(n), (s, n, n))
+        post = GPPosterior(x_train=jnp.zeros((n, d)), mask=jnp.ones(n, bool),
+                           chol=eye, alpha=jnp.zeros((s, n)), params=params,
+                           chol_inv=eye)
+        cfg = AcqOptConfig(num_anchors=16, num_refine=2, refine_steps=2)
+        name = "jax_include_full_tracebacks_in_locations"
+        prev = getattr(jax.config, name)
+        jax.config.update(name, full_tracebacks)
+        try:
+            text = optimize_acquisition.lower(
+                post, jnp.zeros((16, d)), jnp.asarray(0.0), jnp.zeros((4, d)),
+                jnp.zeros(4, bool), jax.random.PRNGKey(0), cfg,
+            ).compile().as_text()
+        finally:
+            jax.config.update(name, prev)
+        scopes = [next((p for p in op.split("/") if p.startswith("acq.")), None)
+                  for op in re.findall(r'op_name="([^"]*)"', text)]
+        assert {"acq.anchors", "acq.refine", "acq.rerank"} <= set(scopes)
+        assert scopes.count("acq.refine") > len(scopes) // 2
