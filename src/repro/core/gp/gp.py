@@ -48,11 +48,13 @@ class GPPosterior(NamedTuple):
     Note: this is a pure pytree (jit/vmap-safe); the gram ``backend`` is
     passed separately as a static argument where needed.
 
-    ``chol_inv`` (optional) caches L⁻¹ for the fused Pallas anchor-scoring
-    kernel (``repro.kernels.acq_score``), whose in-VMEM solve is the matmul
-    L⁻¹K*ᵀ. It is maintained with the same cost profile as the factor: built
-    once per refit (``with_inverse=True``), updated in O(n²) by the rank-1
-    border append, identity-padded on bucket growth."""
+    ``chol_inv`` (optional) caches L⁻¹. ``predict`` then computes L⁻¹k* as
+    a matmul instead of a triangular solve (the acquisition refinement
+    differentiates through it at every step), and the fused Pallas
+    anchor-scoring kernel (``repro.kernels.acq_score``) reads it for its
+    in-VMEM solve. It is maintained with the same cost profile as the
+    factor: built once per refit (``with_inverse=True``), updated in O(n²)
+    by the rank-1 border append, identity-padded on bucket growth."""
 
     x_train: jax.Array  # (n, d) encoded (unwarped) inputs
     mask: jax.Array  # (n,) bool — valid rows
@@ -192,18 +194,27 @@ def predict(
 ) -> tuple[jax.Array, jax.Array]:
     """Posterior marginals at x_star: (mu, var), each (S, m) if the posterior
     holds S MCMC samples, else (m,). Variance includes the latent-f variance
-    only (not observation noise), matching EI-on-f semantics."""
+    only (not observation noise), matching EI-on-f semantics.
+
+    With a cached ``chol_inv`` the solve L⁻¹k* is a full-precision matmul
+    against it; without one it is a triangular solve against ``chol``. The
+    choice rests on the posterior's pytree structure, so it is fixed at
+    trace time."""
     batched = post.chol.ndim == 3
 
-    def one(chol, alpha, params):
+    def one(chol, chol_inv, alpha, params):
         k_star = gram(post.x_train, x_star, params, backend=backend)  # (n, m)
         k_star = k_star * post.mask[:, None].astype(k_star.dtype)
         mu = k_star.T @ alpha  # (m,)
-        v = jax.scipy.linalg.solve_triangular(chol, k_star, lower=True)  # (n, m)
+        if chol_inv is None:
+            v = jax.scipy.linalg.solve_triangular(chol, k_star, lower=True)
+        else:
+            v = jnp.matmul(
+                chol_inv, k_star, precision=jax.lax.Precision.HIGHEST
+            )  # (n, m)
         amp2 = jnp.exp(2.0 * params.log_amplitude)
         var = jnp.maximum(amp2 - jnp.sum(v * v, axis=0), 1e-12)
         return mu, var
 
-    if batched:
-        return jax.vmap(one)(post.chol, post.alpha, post.params)
-    return one(post.chol, post.alpha, post.params)
+    args = (post.chol, post.chol_inv, post.alpha, post.params)
+    return jax.vmap(one)(*args) if batched else one(*args)

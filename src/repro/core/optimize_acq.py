@@ -19,10 +19,13 @@ re-ranking) scores anchors. ``"pallas"`` dispatches EI/LCB to the fused
 predict+acquisition kernel (``repro.kernels.acq_score``): cross-gram,
 cached-Cholesky solve and the closed form run in one VMEM pass per
 (GPHP-sample × anchor-tile), instead of three XLA ops with HBM round-trips.
-Stage 3 (gradient refinement) always evaluates through the XLA composition —
+Stage 3 (gradient refinement) evaluates through the XLA composition —
 ``jax.grad`` must flow through the posterior, which ``pallas_call`` does not
 provide — so the hot dense-grid sweep is fused while the 8-point ascent keeps
-exact gradients.
+exact f64 gradients. The refinement reads the posterior's cached L⁻¹: each
+step's L⁻¹k* (forward and VJP) is a matmul against it, not a triangular
+solve of the factor (``gp.predict``); a posterior without the cache, such as
+a per-head factor, falls back to the solve.
 """
 
 from __future__ import annotations

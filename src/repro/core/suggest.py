@@ -628,9 +628,19 @@ class BOSuggester:
         round-trips to a point not yet seen, else a quasi-random one.
 
         ``suggest.acq_opt`` ends once the candidates are on the host, so it
-        holds the device's work; ``suggest.dedup`` is the host loop."""
+        holds the device's work; ``suggest.dedup`` is the host loop. The
+        counters ``acq.refine.cached_inverse`` and ``acq.refine.solve`` say
+        whether the dispatch's refinement reads a cached L⁻¹ for every
+        posterior it predicts through, or re-solves some factor at every
+        step (per-head factors carry no L⁻¹)."""
         cfg = self.config
         space = self.space
+        posts = (work,) + (head.head_posts if head is not None else ())
+        telemetry.count(
+            "acq.refine.cached_inverse"
+            if all(p.chol_inv is not None for p in posts)
+            else "acq.refine.solve"
+        )
         with telemetry.span(
             "suggest.acq_opt", backend=cfg.acq.backend, slot=slot
         ):
@@ -1390,15 +1400,18 @@ class BOSuggester:
         return jnp.asarray(x_pad), jnp.asarray(y_pad), jnp.asarray(mask)
 
     def _factorize(self, xj, yj, mj):
-        """Factorize the masked rows under the cached GPHP draws. The Pallas
-        anchor-scoring path consumes L⁻¹; build it at factorization time so
-        every decision (and fantasy append) reuses the cached inverse."""
+        """Factorize the masked rows under the cached GPHP draws, with L⁻¹.
+        The acquisition refinement (``gp.predict``) and the Pallas
+        anchor-scoring kernel both read it; building it here, whatever the
+        scoring backend, lets every decision (and fantasy append) reuse the
+        cached inverse and keeps the two backends' refinements in the same
+        arithmetic."""
         params_batch = gpparams.GPHyperParams.unpack(
             jnp.asarray(self.cache.samples), self.space.encoded_dim
         )
         return gplib.fit_posterior_batch(
             xj, yj, params_batch, mj, backend=self.config.fit_backend,
-            with_inverse=self.config.acq.backend == "pallas",
+            with_inverse=True,
         )
 
     def _factorize_with(self, samples, xj, yj, mj):

@@ -211,3 +211,57 @@ def test_refinement_does_not_hurt():
     _, v1 = optimize_acquisition(post, anchors, y_best, jnp.zeros((8, 2)),
                                  jnp.zeros(8, bool), jax.random.PRNGKey(1), cfg1)
     assert float(v1[0]) >= float(v0[0]) - 1e-9
+
+
+def _subjaxprs(eqn):
+    """The jaxprs nested in one equation's params (calls, loop bodies)."""
+    for val in eqn.params.values():
+        for v in val if isinstance(val, (tuple, list)) else (val,):
+            inner = getattr(v, "jaxpr", v)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
+def _primitives(jaxpr, out):
+    """Every primitive name in ``jaxpr``, nested calls and loop bodies too."""
+    for eqn in jaxpr.eqns:
+        out.add(eqn.primitive.name)
+        for inner in _subjaxprs(eqn):
+            _primitives(inner, out)
+    return out
+
+
+def _stage_primitives(jaxpr, stage):
+    """Primitives of the nested jitted call named ``stage``
+    (``optimize_acq._stage``), searched through the whole program."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("pjit", "jit") and eqn.params["name"] == stage:
+            return _primitives(eqn.params["jaxpr"].jaxpr, set())
+        for inner in _subjaxprs(eqn):
+            found = _stage_primitives(inner, stage)
+            if found is not None:
+                return found
+    return None
+
+
+@pytest.mark.parametrize("cached", [True, False], ids=["cached-inverse", "no-cache"])
+def test_refine_solves_only_without_cached_inverse(cached):
+    """The refinement stage reads the cached L⁻¹: its traced program holds no
+    ``triangular_solve`` when the posterior carries L⁻¹, and still holds one
+    when it does not (the fallback for posteriors without the cache)."""
+    x = jnp.asarray(np.random.default_rng(0).random((8, 2)))
+    y = jnp.asarray(np.sin(5 * np.asarray(x[:, 0])))
+    batch = jax.tree.map(lambda a: jnp.stack([a, a]), P.default_params(2))
+    post = G.fit_posterior_batch(x, y, batch, with_inverse=cached)
+    cfg = AcqOptConfig(num_anchors=16, num_refine=2, refine_steps=2)
+    jaxpr = jax.make_jaxpr(
+        lambda post: optimize_acquisition(
+            post, jnp.asarray(sobol_sample(2, 16)), jnp.min(y),
+            jnp.zeros((4, 2)), jnp.zeros(4, bool), jax.random.PRNGKey(0), cfg,
+        )
+    )(post)
+    refine = _stage_primitives(jaxpr.jaxpr, "refine")
+    assert refine is not None and "scan" in refine
+    assert ("triangular_solve" in refine) == (not cached)
+    if cached:
+        assert "dot_general" in refine

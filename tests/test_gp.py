@@ -133,3 +133,48 @@ def test_batched_posterior_matches_single():
     np.testing.assert_allclose(mu_b[0], mu_s, atol=1e-10)
     np.testing.assert_allclose(mu_b[1], mu_s, atol=1e-10)
     np.testing.assert_allclose(var_b[0], var_s, atol=1e-10)
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "S3"])
+@pytest.mark.parametrize("pad", [0, 12], ids=["exact", "bucket-padded"])
+def test_predict_cached_inverse_matches_solve(batched, pad):
+    """``predict`` through the cached L⁻¹ (a matmul) agrees with the
+    triangular solve of the factor in μ, σ² and ∂EI/∂x — the quantities the
+    acquisition refinement reads at every Adam step."""
+    from repro.core.acquisition import expected_improvement
+
+    x, y = _data()
+    n, d = x.shape
+    mask = None
+    if pad:
+        # bucket padding: masked rows whose factor rows are identity
+        x = jnp.concatenate([x, jnp.full((pad, d), 0.37)], axis=0)
+        y = jnp.concatenate([y, jnp.full((pad,), 5.0)], axis=0)
+        mask = jnp.arange(n + pad) < n
+    p = P.default_params(d)
+    if batched:
+        shift = jnp.asarray([-0.3, 0.0, 0.4])
+        p = jax.tree.map(lambda a: jnp.stack([a, a, a]), p)
+        p = p._replace(
+            log_lengthscale=p.log_lengthscale + shift[:, None],
+            log_amplitude=p.log_amplitude - 0.5 * shift,
+        )
+        post_inv = G.fit_posterior_batch(x, y, p, mask, with_inverse=True)
+    else:
+        post_inv = G.fit_gp(x, y, p, mask, with_inverse=True)
+    post_sol = post_inv._replace(chol_inv=None)
+    xs = jnp.asarray(np.random.default_rng(3).random((7, d)))
+    y_best = jnp.min(y[:n])
+
+    def ei(post, xq):
+        mu, var = G.predict(post, xq)
+        return jnp.sum(expected_improvement(mu, var, y_best))
+
+    mu_i, var_i = G.predict(post_inv, xs)
+    mu_s, var_s = G.predict(post_sol, xs)
+    assert mu_i.shape == mu_s.shape == ((3, 7) if batched else (7,))
+    np.testing.assert_allclose(mu_i, mu_s, rtol=1e-10, atol=0)
+    np.testing.assert_allclose(var_i, var_s, rtol=1e-10, atol=0)
+    g_i = jax.grad(ei, argnums=1)(post_inv, xs)
+    g_s = jax.grad(ei, argnums=1)(post_sol, xs)
+    np.testing.assert_allclose(g_i, g_s, rtol=1e-10, atol=0)

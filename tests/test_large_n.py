@@ -516,6 +516,32 @@ class TestPerHeadGPHP:
         assert len(per_head) == 4
         assert shared != per_head
 
+    @pytest.mark.parametrize("per_head", [False, True])
+    def test_refine_counter_names_the_solve_path(self, per_head):
+        """An M=2 job's refinement reads the cached L⁻¹ of the shared factor
+        (``acq.refine.cached_inverse``); per-head factors carry none, so
+        with per_head_gphp every dispatch counts ``acq.refine.solve``."""
+        from repro.core import telemetry
+
+        space = _space()
+        cfg = dataclasses.replace(_EXACT, per_head_gphp=per_head)
+        store = _seeded_store(space, 8, metrics=MetricSet(list(_CONSTRAINED)))
+        sug = BOSuggester(space, cfg, seed=5, store=store)
+        telemetry.get().reset()
+        telemetry.set_enabled(True)
+        try:
+            for _ in range(2):
+                c = sug.suggest_batch(1)[0]
+                store.push_metrics(c, {"loss": _obj(c), "lat": c["x"] + c["y"]})
+            counters = telemetry.get().metrics()["counters"]
+        finally:
+            telemetry.set_enabled(False)
+            telemetry.get().reset()
+        path = "acq.refine.solve" if per_head else "acq.refine.cached_inverse"
+        refine = {k: v for k, v in counters.items()
+                  if k.startswith("acq.refine.")}
+        assert refine == {path: 2}
+
     def test_state_roundtrip_per_head(self):
         space = _space()
         on = dataclasses.replace(_EXACT, per_head_gphp=True)

@@ -710,3 +710,28 @@ class TestProfilerClock:
                   for op in re.findall(r'op_name="([^"]*)"', text)]
         assert {"acq.anchors", "acq.refine", "acq.rerank"} <= set(scopes)
         assert scopes.count("acq.refine") > len(scopes) // 2
+
+
+class TestRefinePath:
+    @pytest.mark.parametrize("backend", ["xla", "pallas"])
+    def test_each_dispatch_counts_the_cached_inverse_path(self, backend):
+        """Every acquisition dispatch counts ``acq.refine.cached_inverse``
+        once, and none counts ``acq.refine.solve``: the engine's factor
+        carries L⁻¹ whatever the scoring backend."""
+        import dataclasses
+
+        from repro.core.optimize_acq import AcqOptConfig
+
+        cfg = dataclasses.replace(
+            _CFG, acq=AcqOptConfig(num_anchors=64, backend=backend))
+        h = SelectionService(ServiceConfig()).register_job(
+            "job", _space(), bo_config=cfg, seed=5)
+        telemetry.set_enabled(True)
+        _drive(h, 6)
+        dispatches = [e for e in telemetry.get().trace_events()
+                      if e["name"] == "suggest.acq_opt"]
+        counters = telemetry.get().metrics()["counters"]
+        refine = {k: v for k, v in counters.items()
+                  if k.startswith("acq.refine.")}
+        assert len(dispatches) == 6 - _CFG.num_init
+        assert refine == {"acq.refine.cached_inverse": len(dispatches)}
