@@ -4,7 +4,8 @@
     python3 bench/calibrate.py --workload <cell> --seeds 1,2,3 --seconds 5 [--fault start]
 
 Runs the cell once per seed in one process (programs compile once), each
-run as ``bench/run.py`` makes it, and prints one JSON line per seed: the
+run as ``bench/run.py`` makes it (all its replicas, one per chip, and its
+client processes), and prints one JSON line per seed: the
 program's numbers (``program``) and those of the float32 control, the
 reference put in the program's place (``control``). The benchmark's own
 runs do not run the control. ``--fault start`` plants a fault first: every

@@ -4,10 +4,13 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell (``BENCHMARK.json``) names a configuration and a traffic mix (see
-``bench/spec.py``). One process holds the chip and runs the served path
-from the client's side: ``RemoteService`` → socket → ``EngineServer`` (a
-thread of this process) → ``SelectionService`` → ``BOSuggester`` →
-``optimize_acquisition`` with the fused ``acq_score`` Pallas kernel.
+``bench/spec.py``). One process holds the cell's chips and runs the served
+path from the client's side: ``RemoteService`` → socket → ``EngineServer``
+(a thread of this process, one replica per chip of the cell, job j leased
+on replica j mod their number) → ``SelectionService`` → ``BOSuggester`` →
+``optimize_acquisition`` with the fused ``acq_score`` Pallas kernel. The
+jobs' clients are threads of this process, or client processes of their own
+on the CPU (the mix's ``client_processes``, ``bench/traffic.py``).
 
 Set-up (``setup_s``, from the start of this script): the persistent
 compilation cache at ``<checkout>/.jax_cache``; the jobs registered and
@@ -23,10 +26,11 @@ per-layer metrics, else its end-to-end ones.
 After the window, a seeded sample of the decision slots (and the last one)
 is compared with the float64 reference of ``bench/reference.py``, and every
 returned configuration is checked: in bounds and distinct from the job's
-history and pending trials. The numbers compared and their limits
-(``bench/limits/<cell>.json``) are printed last on stderr, and last in the
-result line, the last line of stdout. No TPU, or fewer chips than the cell
-asks for: exit 1 and no result.
+history and pending trials. With several replicas, every job must also be
+on the replica it was placed on (``misplaced``). The numbers compared and their
+limits (``bench/limits/<cell>.json``) are printed last on stderr, and last
+in the result line, the last line of stdout. No TPU, or fewer chips than
+the cell asks for: exit 1 and no result.
 """
 
 from __future__ import annotations
@@ -270,6 +274,30 @@ def check_device(chips: int):
     return devices
 
 
+def misplaced(jobs, servers, devices) -> int:
+    """Jobs not on the replica they were placed on (job j on replica j mod
+    their number), after the window: a job counts where a decision of it
+    was answered by another replica (its lease moved), where it is resident
+    on any replica but its own or not on its own, or where its posterior's
+    ``chol``, ``chol_inv`` and ``alpha`` are not all on its replica's
+    chip."""
+    replica_of = {server.address: r for r, server in enumerate(servers)}
+    bad = 0
+    for j, job in enumerate(jobs):
+        r = j % len(servers)
+        answered = {replica_of.get(d["replica"]) for d in job.decisions}
+        resident = [i for i, server in enumerate(servers)
+                    if job.name in server.service._jobs]
+        handle = servers[r].service._jobs.get(job.name)
+        post = None if handle is None else handle.suggester.cache.post
+        on_chip = post is not None and all(
+            set(a.devices()) == {devices[r]}
+            for a in (post.chol, post.chol_inv, post.alpha))
+        if answered != {r} or resident != [r] or not on_chip:
+            bad += 1
+    return bad
+
+
 def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
              require_tpu=True, control=False, log=print):
     """One run of ``cell``; returns the result line's object (with
@@ -280,9 +308,9 @@ def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
     from bench import spec
     from bench import trace as tracing
     from bench.reference import decision_gaps, encode, fit_gap, standardize
-    from bench.traffic import Job, job_seeds
+    from bench.traffic import clients
     from repro.core import telemetry
-    from repro.distributed import EngineServer, RemoteService
+    from repro.distributed import EngineServer
 
     chips = cell["chips"]
     if require_tpu:
@@ -296,66 +324,24 @@ def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
         f"count={len(devices)}")
 
     space = cfg["space"]
-    history = mix.get("history", cfg["history"])
     # the engine's registry, with room for every span of the window
     registry, prior = telemetry.Telemetry(trace_capacity=TRACE_CAPACITY), \
         telemetry._GLOBAL
     telemetry._GLOBAL = registry
     recorder = Recorder()
     counter = CompileCounter.get()
-    server = EngineServer(service_config=spec.service_config(cfg),
-                          lease_ttl=cfg["server"]["lease_ttl"],
-                          device=dev).start()
-    jobs = []
+    servers, jobs_client = [], None
     try:
-        client = RemoteService([server.address],
-                               bo_config=spec.bo_config(cfg),
-                               **cfg["client"])
-        prog_space = spec.search_space(cfg)
-        for j in range(cfg["jobs"]):
-            data_seed, engine_seed, objective_seed = job_seeds(
-                seed, j, mix.get("shared_objective", False))
-            name = f"job-{j}"
-            handle = client.register_job(name, prog_space, seed=engine_seed,
-                                         fold_siblings=False)
-            job = Job(name, handle, space, mix, cfg["workers"], data_seed,
-                      objective_seed)
-            job.preload(history)
-            jobs.append(job)
-
-        window = {}
-        barrier = threading.Barrier(len(jobs) + 1)
-        errors = []
-
-        def drive(job):
-            try:
-                job.fill()
-                for _ in range(mix["warmup_steps"]):
-                    job.step()
-            except Exception as e:  # noqa: BLE001 — set-up failed: no result
-                errors.append(f"{job.name} set-up: {type(e).__name__}: {e}")
-                barrier.abort()
-                return
-            try:
-                barrier.wait()
-            except threading.BrokenBarrierError:
-                return
-            while time.monotonic() < window["deadline"]:
-                try:
-                    job.step()
-                except Exception:  # noqa: BLE001 — counted in job.failed
-                    return
-
-        threads = [threading.Thread(target=drive, args=(job,), daemon=True)
-                   for job in jobs]
-        for t in threads:
-            t.start()
+        # one replica per chip, in this process
+        for r in range(chips):
+            servers.append(EngineServer(
+                service_config=spec.service_config(cfg),
+                lease_ttl=cfg["server"]["lease_ttl"],
+                device=devices[r]).start())
+        jobs_client = clients(cfg, mix, seed, [s.address for s in servers])
         # set-up finishes with every job at its barrier; check the kernel
         # while they wait, then open the window
-        while barrier.n_waiting < len(jobs) and not errors:
-            time.sleep(0.01)
-        if errors:
-            raise RuntimeError("; ".join(errors))
+        jobs_client.ready()
         args = recorder.last_setup_call
         if require_tpu:
             if args is None or "tpu_custom_call" not in recorder.acq.lower(
@@ -371,8 +357,7 @@ def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
         setup_s = time.monotonic() - T_START
         compiles0 = counter.total()
         t0 = time.monotonic()
-        window["deadline"] = t0 + seconds
-        barrier.wait()
+        jobs_client.go(t0 + seconds)
         if trace:
             # host annotations and runtime events, not every Python call
             options = jax.profiler.ProfileOptions()
@@ -381,23 +366,24 @@ def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
             with jax.profiler.TraceAnnotation(tracing.WINDOW):
                 time.sleep(min(mix["trace_seconds"], seconds))
             jax.profiler.stop_trace()
-        for t in threads:
-            t.join()
-        t1 = time.monotonic()
+        t1, jobs = jobs_client.finish()
         compiles = counter.total() - compiles0
         recorder.stop()
         registry.set_enabled(False)
         log(f"window: {t1 - t0:.3f} s; compilations inside it: {compiles}")
-        stats = dev.memory_stats() or {}
-        device["memory_peak_bytes"] = int(stats.get("peak_bytes_in_use", 0))
+        device["memory_peak_bytes"] = max(
+            int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devices[:chips])
         calls = recorder.calls()
         spans = [e for e in registry.trace_events()
                  if e["kind"] == "span" and e["t0"] >= t0 and e["t1"] <= t1]
         counters = registry.metrics()["counters"]
-        for job in jobs:
-            job.handle.close()
+        placed = misplaced(jobs, servers, devices) if chips > 1 else None
     finally:
-        server.shutdown()
+        if jobs_client is not None:
+            jobs_client.close()
+        for server in servers:
+            server.shutdown()
         recorder.uninstall()
         telemetry._GLOBAL = prior
 
@@ -407,6 +393,12 @@ def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
     for d in decisions:
         if "error" in d:
             log(f"failed decision: {d['error']}")
+    on_time = sum(1 for d in decisions if d["t1"] <= t0 + seconds)
+    ms = [(d["t1"] - d["t0"]) * 1e3 for d in decisions if "error" not in d]
+    if ms:
+        log(f"decisions: {attempted}, {on_time} returned by the deadline; "
+            f"latency p50 {np.percentile(ms, 50):.3f} ms, "
+            f"p90 {np.percentile(ms, 90):.3f} ms")
 
     # --- correctness: sampled slots against the reference, on the rows and
     # pending trials of the client's own record; every config
@@ -446,6 +438,9 @@ def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
     readings["bad_configs"] = sum(job.bad_configs(t0) for job in jobs)
     readings["failed"] = failed
     limits = dict(limits, unmatched=0, failed=0)
+    if placed is not None:
+        readings["misplaced"] = placed
+        limits.setdefault("misplaced", 0)
     # a number missing or not finite reads as the largest float (JSON has
     # no infinity)
     checks = {k: {"value": min(readings.get(k, sys.float_info.max),
@@ -472,8 +467,9 @@ def run_cell(cell, cfg, mix, limits, seed, seconds, trace, *,
         peaks = None
     # what the metric readers read
     run = types.SimpleNamespace(
-        decisions=decisions, window=(t0, t1), spans=spans, counters=counters,
-        trace=device_trace, peaks=peaks, setup_s=setup_s)
+        decisions=decisions, window=(t0, t1), deadline=t0 + seconds,
+        spans=spans, counters=counters, trace=device_trace, peaks=peaks,
+        setup_s=setup_s, replicas=chips)
     kind = "per_layer" if trace else "end_to_end"
     metrics = {}
     for m in spec.cell_metrics(cell["name"], kind):

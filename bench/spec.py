@@ -1,7 +1,8 @@
 """Resolve a cell of ``BENCHMARK.json`` to its files, by name.
 
 A cell names a configuration (``bench/configs/<config>.json``) and a traffic
-mix (``bench/traffic/<traffic>.json``); each metric is read by
+mix (``bench/traffic/<traffic>.json``), and is served by one one-chip
+replica per chip it asks for (``bench/run.py``); each metric is read by
 ``bench/metrics/<module>.py``, where the module is the metric's name with
 ``.`` and ``-`` written as ``_``; each kernel's work count is
 ``bench/work/<kernel>.py``; the correctness limits of a cell are in

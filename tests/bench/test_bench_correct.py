@@ -26,8 +26,10 @@ def _failed(result):
     return [k for k, c in result["checks"].items() if c["value"] > c["limit"]]
 
 
-@pytest.mark.parametrize("name", [c["name"] for c in spec.benchmark()["workloads"]])
+@pytest.mark.parametrize("name", [
+    c["name"] for c in spec.benchmark()["workloads"] if c["chips"] == 1])
 def test_sound_run_is_correct_and_control_is_not(small_cell, name):
+    # cells of several replicas: tests/bench/test_bench_fleet.py
     # 150 rows: conditioned like the cells' histories, where float32 fails
     result = _run(small_cell, name, control=True, history=150)
     assert result["correct"], result["checks"]
